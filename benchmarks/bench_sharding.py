@@ -131,6 +131,7 @@ def _compute_fleet(n_shards: int, batch: int, batches_per_tenant: int) -> dict:
                            "nonce": jnp.arange(3, dtype=jnp.uint32) + 7}}
     sb = ShardedBackend(
         [ComputeBackend(use_fused=False, name=f"c{i}",
+                        device=i % len(jax.devices()),
                         quantum_bytes=batch * WIRE_BYTES_PER_PKT)
          for i in range(n_shards)],
         auto_rebalance=False)

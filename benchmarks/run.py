@@ -29,6 +29,7 @@ from benchmarks.bench_fairness import bench_fairness_summary
 from benchmarks.bench_resilience import bench_resilience_summary
 from benchmarks.bench_scenarios import bench_scenarios_summary
 from benchmarks.bench_sharding import bench_sharding_summary
+from repro.compile_cache import enable_compile_cache
 
 REPO_ROOT = Path(__file__).resolve().parents[1]
 FIG_OUT = REPO_ROOT / "experiments" / "bench"
@@ -98,6 +99,7 @@ def main(argv=None) -> int:
     if not names:
         names = list(ALL)
 
+    enable_compile_cache()
     fig_out = out_dir if out_dir is not None else FIG_OUT
     fig_out.mkdir(parents=True, exist_ok=True)
     if out_dir is not None:

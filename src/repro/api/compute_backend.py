@@ -192,9 +192,15 @@ def _chacha_nt(state, params):
                                         ctr=ctr)}
 
 
+def _ctr_run(c0: int, n: int) -> np.ndarray:
+    """Host-side counter run ``c0 + arange(n)`` (u32, wrapping): per-packet
+    state stays with the packet data on the host until the dispatch ships
+    it to the launch's own device."""
+    return np.uint32(c0) + np.arange(n, dtype=np.uint32)
+
+
 def _chacha_prep(n, params):
-    c0 = params.get("counter0", 1)
-    return {"ctr": jnp.uint32(c0) + jnp.arange(n, dtype=jnp.uint32)}
+    return {"ctr": _ctr_run(params.get("counter0", 1), n)}
 
 
 def _chacha_stream(n, params, state):
@@ -210,8 +216,7 @@ def _chacha_stream(n, params, state):
     nxt = int(state.get("next_ctr", params.get("counter0", 1)))
     if params.get("scalar_ctr"):
         return ({"ctr0": jnp.uint32(nxt)}, {"next_ctr": nxt + n})
-    return ({"ctr": jnp.uint32(nxt) + jnp.arange(n, dtype=jnp.uint32)},
-            {"next_ctr": nxt + n})
+    return {"ctr": _ctr_run(nxt, n)}, {"next_ctr": nxt + n}
 
 
 BUILTIN_COMPUTE_NTS: dict[str, ComputeNT] = {
@@ -300,6 +305,17 @@ class _Deployment:
     #: per-NT running stream state (plain scalars, checkpointable); only
     #: advanced at dispatch time, so it always reflects completed work
     nt_state: dict[str, dict] = field(default_factory=dict)
+    #: pinned device -> ``params`` with its arrays committed there, so a
+    #: dispatch to a pinned device never copies rules and keys per launch
+    device_params: dict = field(default_factory=dict)
+
+
+def _commit(params: dict, dev) -> dict:
+    """``params`` with every array leaf committed to ``dev``; plain Python
+    values (flags, counters) stay host-side."""
+    return jax.tree.map(
+        lambda x: jax.device_put(x, dev) if hasattr(x, "shape") else x,
+        params)
 
 
 def _rows(batch: dict) -> int:
@@ -415,6 +431,26 @@ def _corrupt_batch(batch: dict, rng) -> dict:
     out = dict(batch)
     out["payload"] = flat.reshape(a.shape)
     return out
+
+
+def _bucket_state(batches: list[dict], bucket: int, n: int, dev) -> dict:
+    """A batch-mode dispatch group as one fresh input tree: coalesced and
+    padded to ``bucket`` rows with the ``valid`` row mask, built on
+    ``dev`` (the caller's default device when None) and committed there,
+    so the jitted program runs on the shard's device without a copy."""
+    state = {}
+    with jax.default_device(dev):
+        for k, v in batches[0].items():
+            if hasattr(v, "shape") and getattr(v, "ndim", 0) >= 1:
+                state[k] = _fill_bucket([b[k] for b in batches], bucket)
+            elif hasattr(v, "shape"):             # 0-d: fresh copy
+                state[k] = _pad_to(v, bucket)
+            else:
+                state[k] = v
+        state["valid"] = jnp.arange(bucket, dtype=jnp.int32) < n
+    if dev is not None:
+        state = jax.device_put(state, dev)        # resident: commits, no copy
+    return state
 
 
 def _slice_result(out: dict, off: int, s: int) -> dict:
@@ -653,7 +689,9 @@ class ComputeBackend:
             if factory is not None:
                 fused = factory(params)
         self.deployments[dag.uid] = _Deployment(
-            dag, params, fused, self._composed_program(dag))
+            dag, params, fused, self._composed_program(dag),
+            device_params={d: _commit(params, d)
+                           for d in self.devices or ()})
 
     def inject(self, tenant: str, dag_uid: int, state: dict | None = None,
                **fields) -> None:
@@ -800,18 +838,13 @@ class ComputeBackend:
         return groups, enq_at
 
     def _launch(self, dep: _Deployment, batches: list[dict], bucket: int,
-                state: dict) -> dict:
-        """Common tail of both dispatch paths: device pin + program call."""
-        dev = self._next_device()
-        if dev is not None:
-            # explicit shard device: commit the whole input tree so the
-            # jitted program executes there (device_put copies — donation
-            # stays safe, and the transfer is async: it overlaps whatever
-            # kernel is already running)
-            state = jax.device_put(state, dev)
+                state: dict, dev) -> dict:
+        """Common tail of both dispatch paths: the program call, on ``dev``
+        (where ``state`` is already committed) or the default device."""
+        params = dep.device_params[dev] if dev is not None else dep.params
         path = ("fused" if dep.fused is not None
                 and "allow" not in batches[0] else "composed")
-        out = self._get_program(dep, bucket, path)(state, dep.params)
+        out = self._get_program(dep, bucket, path)(state, params)
         self.stats["dispatches"] += 1
         if path == "fused":
             self.stats["fused_dispatches"] += 1
@@ -845,17 +878,9 @@ class ComputeBackend:
             bucket = bucket_size(n)
             if len(batches) > 1:
                 self.stats["coalesced_batches"] += len(batches)
-            state = {}
-            for k, v in batches[0].items():
-                if hasattr(v, "shape") and getattr(v, "ndim", 0) >= 1:
-                    state[k] = _fill_bucket([b[k] for b in batches], bucket)
-                elif hasattr(v, "shape"):         # 0-d: fresh copy
-                    state[k] = _pad_to(v, bucket)
-                else:
-                    state[k] = v
-            state["valid"] = (
-                jnp.arange(bucket, dtype=jnp.int32) < n)
-            out = self._launch(dep, batches, bucket, state)
+            dev = self._next_device()
+            state = _bucket_state(batches, bucket, n, dev)
+            out = self._launch(dep, batches, bucket, state, dev)
             launched.append((dep, orders, sizes, out))
 
         jax.block_until_ready([o for *_, o in launched])    # the ONE sync
@@ -915,8 +940,9 @@ class ComputeBackend:
             state[k] = _pad_to(v, bucket) if hasattr(v, "shape") else v
         if self._t_first is None:
             self._t_first = time.perf_counter()   # streaming window opens
-        state = jax.device_put(state)             # async H2D of the slot
-        out = self._launch(dep, batches, bucket, state)
+        dev = self._next_device()
+        state = jax.device_put(state, dev)        # async H2D of the slot
+        out = self._launch(dep, batches, bucket, state, dev)
         self.inflight_batches += len(orders)
         self.stats["stream_batches"] += len(orders)
         return _InFlight(dep, orders, sizes, out, ring_slot, enq)

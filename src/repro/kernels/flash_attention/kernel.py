@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -105,7 +103,7 @@ def flash_attention_grouped(q, k, v, *, block_q: int = 256,
             pltpu.VMEM((G * bq, 1), jnp.float32),
             pltpu.VMEM((G * bq, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel",
                                  "parallel", "arbitrary")),
         interpret=interpret,

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _mamba_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, h_ref,
                   *, C: int):
@@ -79,7 +77,7 @@ def mamba_ssm(x, dt, Bmat, Cmat, A, D, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, C, dib), lambda b, i, c: (b, c, i)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((dib, ds), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, Bmat, Cmat, A, D.reshape(1, di))

@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_ref, *, C: int):
     ci = pl.program_id(2)
@@ -71,7 +69,7 @@ def rwkv6_wkv(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(r.shape, r.dtype),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

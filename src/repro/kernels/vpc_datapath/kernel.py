@@ -37,9 +37,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas.tpu import CompilerParams
 
 from repro.kernels.chacha20.core import chacha_rounds, init_state
-from repro.kernels.compat import CompilerParams
 
 
 def _vpc_datapath_kernel(prefixes_ref, masks_ref, mlen_ref, rallow_ref,
@@ -56,13 +56,23 @@ def _vpc_datapath_kernel(prefixes_ref, masks_ref, mlen_ref, rallow_ref,
     ridx = jax.lax.broadcasted_iota(jnp.int32, (1, n_rules), 1)
     prio = jnp.where(hit, mlen * n_rules + (n_rules - 1 - ridx), -1)
     best = jnp.max(prio, axis=1, keepdims=True)           # (bn, 1)
-    rallow = rallow_ref[...] != 0                         # (1, R)
-    win_allow = jnp.any(hit & (prio == best) & rallow, axis=1)
-    allow = jnp.where(jnp.any(hit, axis=1), win_allow, True)   # (bn,)
+    # the verdict stays int32 (0/1) until egress: Mosaic cannot lower a
+    # reduction or select over bool vectors (i8 -> i1 truncation).  Hit
+    # priorities are unique, so ``prio == best`` with ``best >= 0`` picks
+    # exactly the winning rule; ``best < 0`` is "no hit" -> default allow.
+    rallow = rallow_ref[...].astype(jnp.int32)            # (1, R) 0/1
+    win_allow = jnp.max(jnp.where(prio == best, rallow, 0), axis=1,
+                        keepdims=True)                    # (bn, 1)
+    allow_i = jnp.where(best >= 0, win_allow, 1)          # (bn, 1) int32
+    allow = allow_i != 0                                  # (bn, 1) mask
 
     # ---- NT 2: NAT source rewrite (flow-hash port, fixed ip) ----
+    # ``x << 16`` is taken as two shifts of 8: on a v5e, Mosaic lowers a
+    # lone u32 shift-left by 16 through a float path that flushes results
+    # whose bits read as an f32 denormal or NaN (~1 in 128 values wrong).
+    sport_hi = (headers[:, 2] << jnp.uint32(8)) << jnp.uint32(8)
     flow = headers[:, 0] ^ (headers[:, 1] * jnp.uint32(2654435761)) \
-        ^ (headers[:, 2] << jnp.uint32(16)) ^ headers[:, 3] ^ headers[:, 4]
+        ^ sport_hi ^ headers[:, 3] ^ headers[:, 4]
     new_port = ((flow * jnp.uint32(salt)) >> jnp.uint32(16)) \
         & jnp.uint32(0xFFFF)
     col = jax.lax.broadcasted_iota(jnp.int32, (bn, 5), 1)
@@ -79,11 +89,12 @@ def _vpc_datapath_kernel(prefixes_ref, masks_ref, mlen_ref, rallow_ref,
     payload = payload_ref[...]                            # (bn, 16)
 
     # ---- egress: apply the firewall verdict in the same pass ----
-    allow_ref[:, 0] = allow.astype(jnp.uint32)
-    hout_ref[...] = jnp.where(allow[:, None], nat_h, headers)
+    allow_ref[...] = allow_i.astype(jnp.uint32)
+    hout_ref[...] = jnp.where(allow, nat_h, headers)
     for w in range(16):
         ks = s[w] + init[w]                               # final add
-        pout_ref[:, w] = jnp.where(allow, payload[:, w] ^ ks, jnp.uint32(0))
+        pout_ref[:, w] = jnp.where(allow[:, 0], payload[:, w] ^ ks,
+                                   jnp.uint32(0))
 
 
 def vpc_datapath_kernel_call(headers, payload, ctr, prefixes, masks, mlen,

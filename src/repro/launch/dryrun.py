@@ -21,7 +21,7 @@ from pathlib import Path  # noqa: E402
 import jax  # noqa: E402
 
 from repro import configs  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.mesh import make_mesh, make_production_mesh  # noqa: E402
 from repro.launch.steps import input_specs  # noqa: E402
 from repro.parallel import sharding as SH  # noqa: E402
 from repro.parallel import ctx as pctx  # noqa: E402
@@ -78,7 +78,7 @@ def make_mesh_by_name(mesh_name: str):
     if mesh_name in ("single", "multi"):
         return make_production_mesh(multi_pod=(mesh_name == "multi"))
     d, m = (int(x) for x in mesh_name.split("x"))
-    return jax.make_mesh((d, m), ("data", "model"))
+    return make_mesh((d, m), ("data", "model"))
 
 
 def run_cell(arch: str, shape_name: str, mesh_name: str,

@@ -7,19 +7,27 @@ any jax import; everything else sees the real device count.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``.  Newer JAX defaults to
+    ``Explicit`` axes, which ``with_sharding_constraint`` (the activation
+    policy in ``repro.parallel.ctx``) refuses."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0):
     """Small mesh over however many (possibly forced-host) devices exist."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 MESHES = {

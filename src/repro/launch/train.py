@@ -25,6 +25,7 @@ import numpy as np
 from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.data import SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import abstract_params, make_train_step, moment_dtype_for
 from repro.optim import adamw
 from repro.optim.compress import GradCompressor
@@ -38,8 +39,8 @@ def parse_mesh(spec: str):
     avail = len(jax.devices())
     assert n <= avail, f"mesh {spec} needs {n} devices, have {avail}"
     if len(parts) == 2:
-        return jax.make_mesh(tuple(parts), ("data", "model"))
-    return jax.make_mesh(tuple(parts), ("pod", "data", "model"))
+        return make_mesh(tuple(parts), ("data", "model"))
+    return make_mesh(tuple(parts), ("pod", "data", "model"))
 
 
 def get_cfg(name: str):

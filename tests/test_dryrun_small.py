@@ -59,8 +59,8 @@ sh = orig_shapes[{shape!r}]
 configs.SHAPES[{shape!r}] = ShapeConfig(sh.name, 256, 8, sh.kind)
 
 import repro.launch.dryrun as DR
-DR.make_mesh_by_name = lambda name: __import__("jax").make_mesh(
-    (4, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+DR.make_mesh_by_name = lambda name: make_mesh((4, 2), ("data", "model"))
 rec = DR.run_cell({arch!r}, {shape!r}, "single",
                   out_dir=Path({str(tmp_path)!r}), verbose=False)
 assert rec["cost"]["flops"] > 0

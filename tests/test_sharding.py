@@ -111,6 +111,7 @@ import jax, numpy as np
 import jax.numpy as jnp
 from repro import configs
 from repro.data import SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import init_params
 from repro.optim import adamw
@@ -127,7 +128,7 @@ p1, o1, m1 = jax.jit(step)(params, opt, batch)
 l1 = float(m1["loss"])
 
 # sharded 4x2
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 pspec = SH.param_specs(params, mesh)
 with mesh, pctx.policy(mesh):
     sharded = jax.jit(step, in_shardings=(
