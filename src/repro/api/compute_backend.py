@@ -89,6 +89,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.analysis import invariants as _sanitize
 from repro.core.nt import GBPS, NTDag, NTSpec
 from repro.core.sched import FairScheduler, SchedConfig
@@ -552,7 +553,11 @@ class ComputeBackend:
         self._elapsed_s = 0.0
         self.stats = {"traces": 0, "dispatches": 0, "fused_dispatches": 0,
                       "batches": 0, "coalesced_batches": 0, "runs": 0,
-                      "stream_batches": 0, "stream_epochs": 0}
+                      "stream_batches": 0, "stream_epochs": 0,
+                      # bucket rows, and pad rows among them, over launches
+                      "rows_launched": 0, "pad_rows": 0}
+        #: host seconds inside each of the runtime's spans (``repro.obs``)
+        self.span_s = dict.fromkeys(obs.PHASES, 0.0)
         #: batches fully dispatched + synced (I-BATCH conservation: this +
         #: sched.pending() + shed_batches == stats["batches"]); kept out of
         #: ``stats`` so report().extra is unchanged
@@ -841,11 +846,15 @@ class ComputeBackend:
                 state: dict, dev) -> dict:
         """Common tail of both dispatch paths: the program call, on ``dev``
         (where ``state`` is already committed) or the default device."""
+        n = sum(_rows(b) for b in batches)
         params = dep.device_params[dev] if dev is not None else dep.params
         path = ("fused" if dep.fused is not None
                 and "allow" not in batches[0] else "composed")
-        out = self._get_program(dep, bucket, path)(state, params)
+        with obs.span(obs.LAUNCH, self.span_s, rows=n, bucket=bucket):
+            out = self._get_program(dep, bucket, path)(state, params)
         self.stats["dispatches"] += 1
+        self.stats["rows_launched"] += bucket
+        self.stats["pad_rows"] += bucket - n
         if path == "fused":
             self.stats["fused_dispatches"] += 1
         return out
@@ -866,7 +875,8 @@ class ComputeBackend:
             return
         t0 = time.perf_counter()
         # fair service order: the whole pending set, interleaved by weight
-        groups, enq_at = self._fair_groups(self.sched.drain())
+        with obs.span(obs.SCHED_ORDER, self.span_s):
+            groups, enq_at = self._fair_groups(self.sched.drain())
 
         launched = []
         for (dag_uid, _sig), entries in groups:
@@ -879,25 +889,28 @@ class ComputeBackend:
             if len(batches) > 1:
                 self.stats["coalesced_batches"] += len(batches)
             dev = self._next_device()
-            state = _bucket_state(batches, bucket, n, dev)
+            with obs.span(obs.STAGE, self.span_s):
+                state = _bucket_state(batches, bucket, n, dev)
             out = self._launch(dep, batches, bucket, state, dev)
             launched.append((dep, orders, sizes, out))
 
-        jax.block_until_ready([o for *_, o in launched])    # the ONE sync
+        with obs.span(obs.SYNC, self.span_s):
+            jax.block_until_ready([o for *_, o in launched])  # the ONE sync
         t_done = time.perf_counter()
         self._elapsed_s += t_done - t0
         self.stats["runs"] += 1
         for tenant, t_enq in enq_at.values():   # inject -> sync completion
             self._lat_s.setdefault(tenant, []).append(t_done - t_enq)
 
-        split = []                # un-coalesce, drop pad rows
-        for dep, orders, sizes, out in launched:
-            off = 0
-            for order, s in zip(orders, sizes):
-                split.append((order, dep, _slice_result(out, off, s)))
-                off += s
-        for _, dep, res in sorted(split, key=lambda t: t[0]):
-            dep.results.append(res)       # results stay in inject order
+        with obs.span(obs.SPLIT, self.span_s):
+            split = []            # un-coalesce, drop pad rows
+            for dep, orders, sizes, out in launched:
+                off = 0
+                for order, s in zip(orders, sizes):
+                    split.append((order, dep, _slice_result(out, off, s)))
+                    off += s
+            for _, dep, res in sorted(split, key=lambda t: t[0]):
+                dep.results.append(res)   # results stay in inject order
         self.completed_batches += len(enq_at)
         if _sanitize.enabled():           # end-of-drain conservation audit
             _sanitize.check_compute(self, self.name)
@@ -916,32 +929,36 @@ class ComputeBackend:
         bucket = bucket_size(n)
         if len(batches) > 1:
             self.stats["coalesced_batches"] += len(batches)
-        template = batches[0]
-        fields = [(k, tuple(v.shape[1:]), np.dtype(str(v.dtype)))
-                  for k, v in template.items()
-                  if hasattr(v, "shape") and getattr(v, "ndim", 0) >= 1]
-        ring_slot = self.ring.acquire(bucket, fields)
-        off = 0
-        for b, m in zip(batches, sizes):
+        with obs.span(obs.STAGE, self.span_s):
+            template = batches[0]
+            fields = [(k, tuple(v.shape[1:]), np.dtype(str(v.dtype)))
+                      for k, v in template.items()
+                      if hasattr(v, "shape")
+                      and getattr(v, "ndim", 0) >= 1]
+            ring_slot = self.ring.acquire(bucket, fields)
+            off = 0
+            for b, m in zip(batches, sizes):
+                for k, _trail, _dt in fields:
+                    # host->host staging copy: inject batches are
+                    # host-resident packet data, so filling the ring slot
+                    # never syncs a device
+                    dst = ring_slot.staging[k]
+                    dst[off:off + m] = np.asarray(b[k])  # noqa: L-HOSTSYNC
+                off += m
             for k, _trail, _dt in fields:
-                # host->host staging copy: inject batches are host-resident
-                # packet data, so filling the ring slot never syncs a device
-                ring_slot.staging[k][off:off + m] = np.asarray(b[k])  # noqa: L-HOSTSYNC
-            off += m
-        for k, _trail, _dt in fields:
-            ring_slot.staging[k][n:] = 0          # pad rows (exact fill: noop)
-        valid = ring_slot.staging["valid"]
-        valid[:n] = True
-        valid[n:] = False
-        state = dict(ring_slot.staging)
-        for k, v in template.items():             # 0-d / non-array fields
-            if k in state:
-                continue
-            state[k] = _pad_to(v, bucket) if hasattr(v, "shape") else v
-        if self._t_first is None:
-            self._t_first = time.perf_counter()   # streaming window opens
-        dev = self._next_device()
-        state = jax.device_put(state, dev)        # async H2D of the slot
+                ring_slot.staging[k][n:] = 0      # pad rows (exact fill: noop)
+            valid = ring_slot.staging["valid"]
+            valid[:n] = True
+            valid[n:] = False
+            state = dict(ring_slot.staging)
+            for k, v in template.items():         # 0-d / non-array fields
+                if k in state:
+                    continue
+                state[k] = _pad_to(v, bucket) if hasattr(v, "shape") else v
+            if self._t_first is None:
+                self._t_first = time.perf_counter()   # window opens
+            dev = self._next_device()
+            state = jax.device_put(state, dev)    # async H2D of the slot
         out = self._launch(dep, batches, bucket, state, dev)
         self.inflight_batches += len(orders)
         self.stats["stream_batches"] += len(orders)
@@ -950,16 +967,18 @@ class ComputeBackend:
     def _retire(self, slot_entry: _InFlight) -> None:
         """Drain one ring entry: the ONLY per-slot sync, taken when the
         bounded in-flight window wraps (or at the final flush)."""
-        jax.block_until_ready(slot_entry.out)
+        with obs.span(obs.SYNC, self.span_s):
+            jax.block_until_ready(slot_entry.out)
         t_done = time.perf_counter()
         self._t_last = t_done
-        off = 0
-        for order, s in zip(slot_entry.orders, slot_entry.sizes):
-            # per-tenant FIFO + per-dep single tenant => retire order is
-            # inject order for every deployment
-            slot_entry.dep.results.append(
-                _slice_result(slot_entry.out, off, s))
-            off += s
+        with obs.span(obs.SPLIT, self.span_s):
+            off = 0
+            for order, s in zip(slot_entry.orders, slot_entry.sizes):
+                # per-tenant FIFO + per-dep single tenant => retire order
+                # is inject order for every deployment
+                slot_entry.dep.results.append(
+                    _slice_result(slot_entry.out, off, s))
+                off += s
         for tenant, t_enq in slot_entry.enq:      # inject -> slot drain
             self._lat_s.setdefault(tenant, []).append(t_done - t_enq)
         if slot_entry.slot is not None:
@@ -972,7 +991,8 @@ class ComputeBackend:
         each group, retiring the oldest in-flight entry whenever the
         window exceeds ``max_inflight`` — launches and drains interleave,
         so transfer and compute overlap across groups."""
-        groups, enq_at = self._fair_groups(entries)
+        with obs.span(obs.SCHED_ORDER, self.span_s):
+            groups, enq_at = self._fair_groups(entries)
         for (dag_uid, _sig), group in groups:
             dep = self.deployments[dag_uid]
             orders = [order for order, _ in group]

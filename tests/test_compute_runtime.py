@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
+from repro import obs
 from repro.api import (ComputeBackend, ComputeNT, Platform, VPC_SPECS,
                        bucket_size, nt)
 from repro.serving.vpc import make_packets, make_rules, vpc_chain
@@ -252,3 +253,33 @@ class TestComputeRuntime:
     def test_bucket_size_powers_of_two(self):
         assert [bucket_size(n) for n in (1, 8, 9, 100, 256, 257)] == \
             [8, 8, 16, 128, 256, 512]
+
+    @pytest.mark.parametrize("sizes, bucket, pad", [
+        ([1024] * 3, 4096, 1024),          # 3072 rows padded to 4096
+        ([16384] * 2, 32768, 0),           # an exact fit pads nothing
+    ])
+    def test_launch_counts_bucket_and_pad_rows(self, sizes, bucket, pad):
+        plat, dep = vpc_platform(use_fused=False)
+        for i, n in enumerate(sizes):
+            h, p = make_packets(n, seed=30 + i)
+            dep.inject(headers=h, payload=p)
+        plat.run()
+        stats = plat.backend.stats
+        assert stats["dispatches"] == 1
+        assert (stats["rows_launched"], stats["pad_rows"]) == (bucket, pad)
+
+    @pytest.mark.parametrize("stream", [False, True])
+    def test_run_fills_the_phase_totals(self, stream):
+        """Both engines time the same five spans, and only those."""
+        plat, dep = vpc_platform(use_fused=False, stream=stream)
+        for i, n in enumerate([7, 9]):
+            h, p = make_packets(n, seed=40 + i)
+            dep.inject(headers=h, payload=p)
+        plat.run()
+        totals = plat.backend.span_s
+        assert list(totals) == list(obs.PHASES) == [
+            "repro.sched.order", "repro.compute.stage",
+            "repro.compute.launch", "repro.compute.sync",
+            "repro.compute.split"]
+        assert all(v > 0 for v in totals.values()), totals
+        assert plat.backend.stats["rows_launched"] == 16
