@@ -44,10 +44,15 @@ to the host runtime):
   - **One device sync per run().**  Every pending batch is dispatched
     asynchronously; a single ``block_until_ready`` at the end is the only
     host<->device synchronization point, and the throughput window.
+  - **Host staging.**  Every dispatch group is coalesced and padded in
+    host buffers, with its ``valid`` row mask, and crosses to the device
+    in one ``jax.device_put``: each array once, at bucket size, and no
+    eager device op.  Both engines share the fill; batch mode stages in
+    fresh buffers, the streaming engine in its ring slots.
   - **Buffer donation.**  Dispatch inputs are donated to XLA where the
-    backend supports it.  The bucket-padding step always materializes fresh
-    buffers, so caller-owned arrays are never donated (inject the same
-    arrays twice and both runs see identical bits).
+    backend supports it.  Staging always materializes fresh buffers, so
+    caller-owned arrays are never donated (inject the same arrays twice
+    and both runs see identical bits).
   - **Streaming engine** (``run(stream=True)`` / :meth:`inject_stream` /
     ``ComputeBackend(stream=True)``): the pipelined alternative to the
     batch-synchronous drain.  Batches flow through a **dispatch ring** of
@@ -362,10 +367,7 @@ class DispatchRing:
             self.reuses += 1
             return free.pop()
         self.allocs += 1
-        staging = {k: np.zeros((bucket,) + trail, dt)
-                   for k, trail, dt in fields}
-        staging["valid"] = np.zeros((bucket,), bool)
-        return _RingSlot(key, staging)
+        return _RingSlot(key, _staging_buffers(bucket, fields))
 
     def release(self, slot: _RingSlot) -> None:
         self._free.setdefault(slot.key, []).append(slot)
@@ -401,19 +403,56 @@ def _signature(batch: dict):
     return tuple(items)
 
 
-def _fill_bucket(arrays, b: int):
-    """One fresh bucket buffer filled at per-batch offsets: coalescing and
-    pad-to-bucket in a single copy of the packet data (and, like
-    :func:`_pad_to`, never handing a caller-owned buffer to the donated
-    program)."""
-    first = jnp.asarray(arrays[0])
-    buf = jnp.zeros((b,) + first.shape[1:], first.dtype)
+def _array_fields(batch: dict) -> list[tuple[str, tuple[int, ...], np.dtype]]:
+    """``(name, trailing shape, dtype)`` of each packet-axis field."""
+    return [(k, tuple(v.shape[1:]), np.dtype(v.dtype))
+            for k, v in batch.items()
+            if hasattr(v, "shape") and getattr(v, "ndim", 0) >= 1]
+
+
+def _staging_buffers(bucket: int, fields) -> dict[str, np.ndarray]:
+    """Uninitialized host buffers for one dispatch group: one per field of
+    ``fields`` and the ``valid`` row mask, ``bucket`` rows each."""
+    staging = {k: np.empty((bucket,) + trail, dt) for k, trail, dt in fields}
+    staging["valid"] = np.empty((bucket,), bool)
+    return staging
+
+
+def _fill_staging(batches: list[dict], sizes: list[int],
+                  staging: dict[str, np.ndarray]) -> None:
+    """Coalesce and pad a dispatch group into the host buffers ``staging``
+    (from :func:`_staging_buffers` or a ring slot): each batch's rows at
+    its offset, the pad rows zeroed, ``valid`` set on the real rows."""
+    n = sum(sizes)
     off = 0
-    for a in arrays:
-        a = jnp.asarray(a)
-        buf = buf.at[off:off + a.shape[0]].set(a)
-        off += a.shape[0]
-    return buf
+    for b, m in zip(batches, sizes):
+        for k, dst in staging.items():
+            if k != "valid":
+                # host->host staging copy of the packet data (a batch
+                # injected as a jax.Array is read back here, once)
+                dst[off:off + m] = np.asarray(b[k])  # noqa: L-HOSTSYNC
+        off += m
+    for dst in staging.values():
+        dst[n:] = 0                           # pad rows (exact fill: noop)
+    staging["valid"][:n] = True
+
+
+def _ship(template: dict, staging: dict[str, np.ndarray], bucket: int,
+          dev) -> dict:
+    """The filled ``staging`` as the group's input tree on ``dev`` (the
+    default device when None), in one ``jax.device_put``; the 0-d and
+    non-array fields of ``template``, the group's first batch, ride
+    along."""
+    state, rest = dict(staging), {}
+    for k, v in template.items():             # 0-d / non-array fields
+        if k not in state:
+            if hasattr(v, "shape"):
+                state[k] = _pad_to(v, bucket)
+            else:
+                rest[k] = v
+    state = jax.device_put(state, dev)
+    state.update(rest)
+    return state
 
 
 def _corrupt_batch(batch: dict, rng) -> dict:
@@ -432,26 +471,6 @@ def _corrupt_batch(batch: dict, rng) -> dict:
     out = dict(batch)
     out["payload"] = flat.reshape(a.shape)
     return out
-
-
-def _bucket_state(batches: list[dict], bucket: int, n: int, dev) -> dict:
-    """A batch-mode dispatch group as one fresh input tree: coalesced and
-    padded to ``bucket`` rows with the ``valid`` row mask, built on
-    ``dev`` (the caller's default device when None) and committed there,
-    so the jitted program runs on the shard's device without a copy."""
-    state = {}
-    with jax.default_device(dev):
-        for k, v in batches[0].items():
-            if hasattr(v, "shape") and getattr(v, "ndim", 0) >= 1:
-                state[k] = _fill_bucket([b[k] for b in batches], bucket)
-            elif hasattr(v, "shape"):             # 0-d: fresh copy
-                state[k] = _pad_to(v, bucket)
-            else:
-                state[k] = v
-        state["valid"] = jnp.arange(bucket, dtype=jnp.int32) < n
-    if dev is not None:
-        state = jax.device_put(state, dev)        # resident: commits, no copy
-    return state
 
 
 def _slice_result(out: dict, off: int, s: int) -> dict:
@@ -533,8 +552,9 @@ class ComputeBackend:
         # there.  Pass use_fused=True to force (tests/benches do).
         self.use_fused = (jax.default_backend() == "tpu"
                           if use_fused is None else use_fused)
-        # safe because _pad_to always hands the program fresh buffers:
-        # caller-owned arrays are never donated
+        # safe because staging always hands the program fresh buffers
+        # (host staging buffers sent by _ship, and _pad_to's for 0-d
+        # fields): caller-owned arrays are never donated
         self.donate = donate
         self.deployments: dict[int, _Deployment] = {}
         # fair time sharing of the dispatch stream: per-tenant queues served
@@ -555,7 +575,9 @@ class ComputeBackend:
                       "batches": 0, "coalesced_batches": 0, "runs": 0,
                       "stream_batches": 0, "stream_epochs": 0,
                       # bucket rows, and pad rows among them, over launches
-                      "rows_launched": 0, "pad_rows": 0}
+                      "rows_launched": 0, "pad_rows": 0,
+                      # host bytes staging sent to the device
+                      "h2d_bytes": 0}
         #: host seconds inside each of the runtime's spans (``repro.obs``)
         self.span_s = dict.fromkeys(obs.PHASES, 0.0)
         #: batches fully dispatched + synced (I-BATCH conservation: this +
@@ -850,11 +872,15 @@ class ComputeBackend:
         params = dep.device_params[dev] if dev is not None else dep.params
         path = ("fused" if dep.fused is not None
                 and "allow" not in batches[0] else "composed")
-        with obs.span(obs.LAUNCH, self.span_s, rows=n, bucket=bucket):
+        h2d = sum(v.nbytes for v in state.values()
+                  if getattr(v, "ndim", 0) >= 1)   # the staged rows
+        with obs.span(obs.LAUNCH, self.span_s, rows=n, bucket=bucket,
+                      h2d_bytes=h2d):
             out = self._get_program(dep, bucket, path)(state, params)
         self.stats["dispatches"] += 1
         self.stats["rows_launched"] += bucket
         self.stats["pad_rows"] += bucket - n
+        self.stats["h2d_bytes"] += h2d
         if path == "fused":
             self.stats["fused_dispatches"] += 1
         return out
@@ -890,7 +916,10 @@ class ComputeBackend:
                 self.stats["coalesced_batches"] += len(batches)
             dev = self._next_device()
             with obs.span(obs.STAGE, self.span_s):
-                state = _bucket_state(batches, bucket, n, dev)
+                staging = _staging_buffers(bucket,
+                                           _array_fields(batches[0]))
+                _fill_staging(batches, sizes, staging)
+                state = _ship(batches[0], staging, bucket, dev)
             out = self._launch(dep, batches, bucket, state, dev)
             launched.append((dep, orders, sizes, out))
 
@@ -930,35 +959,12 @@ class ComputeBackend:
         if len(batches) > 1:
             self.stats["coalesced_batches"] += len(batches)
         with obs.span(obs.STAGE, self.span_s):
-            template = batches[0]
-            fields = [(k, tuple(v.shape[1:]), np.dtype(str(v.dtype)))
-                      for k, v in template.items()
-                      if hasattr(v, "shape")
-                      and getattr(v, "ndim", 0) >= 1]
-            ring_slot = self.ring.acquire(bucket, fields)
-            off = 0
-            for b, m in zip(batches, sizes):
-                for k, _trail, _dt in fields:
-                    # host->host staging copy: inject batches are
-                    # host-resident packet data, so filling the ring slot
-                    # never syncs a device
-                    dst = ring_slot.staging[k]
-                    dst[off:off + m] = np.asarray(b[k])  # noqa: L-HOSTSYNC
-                off += m
-            for k, _trail, _dt in fields:
-                ring_slot.staging[k][n:] = 0      # pad rows (exact fill: noop)
-            valid = ring_slot.staging["valid"]
-            valid[:n] = True
-            valid[n:] = False
-            state = dict(ring_slot.staging)
-            for k, v in template.items():         # 0-d / non-array fields
-                if k in state:
-                    continue
-                state[k] = _pad_to(v, bucket) if hasattr(v, "shape") else v
+            ring_slot = self.ring.acquire(bucket, _array_fields(batches[0]))
+            _fill_staging(batches, sizes, ring_slot.staging)
             if self._t_first is None:
                 self._t_first = time.perf_counter()   # window opens
             dev = self._next_device()
-            state = jax.device_put(state, dev)    # async H2D of the slot
+            state = _ship(batches[0], ring_slot.staging, bucket, dev)
         out = self._launch(dep, batches, bucket, state, dev)
         self.inflight_batches += len(orders)
         self.stats["stream_batches"] += len(orders)
