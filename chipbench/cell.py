@@ -9,7 +9,8 @@ A loop kind (``loops/<kind>.py``) decides *when* each tenant injects what;
            a pool of packets hands over the pool's rows as ``base``, and
            each packet is first stamped with its index in its tenant's
            stream (:func:`stamp`), so no packet of a run is sent twice;
-  run      ``Platform.run()`` in a ``chipbench.run`` span;
+  run      ``Platform.run()`` in a ``chipbench.run`` span, on the runtime
+           the traffic mix names (:data:`RUNTIMES`);
   retire   ``Platform.report()``, a wait until every output is ready, and
            ``reset_window()`` on each ``ComputeBackend``, in a
            ``chipbench.retire`` span, so outputs never pile up on the device.
@@ -39,6 +40,10 @@ import numpy as np
 from chipbench import reference
 from chipbench.work import chain_bytes
 
+#: a traffic mix's ``runtime`` and the ``ComputeBackend(stream=...)`` it
+#: builds: the batch engine (one sync a ``run()``) or the streaming engine
+#: (a dispatch ring at ``ComputeBackend``'s own depth and in-flight limit)
+RUNTIMES = {"batch": False, "stream": True}
 #: the fields of an output compared with the reference
 COMPARED = ("allow", "headers", "payload")
 #: JAX's monitoring events that mark a new program: a trace, a compile
@@ -239,7 +244,7 @@ class Bench:
 
     def __init__(self, config: dict, seed: int, *, devices=None,
                  backend_kw: dict | None = None, nts: dict | None = None,
-                 check_per_tenant: int = 2):
+                 check_per_tenant: int = 2, runtime: str = "batch"):
         from repro.api import (VPC_SPECS, ComputeBackend, Platform,
                                ShardedBackend, nt)
         self.config = config
@@ -247,7 +252,10 @@ class Bench:
         dep = config["deployment"]
         self.stream_counters = bool(dep.get("stream_counters", False))
         self.tenants = make_tenants(config, seed)
-        kw = dict(backend_kw or {})
+        if runtime not in RUNTIMES:
+            raise ValueError(f"unknown runtime {runtime!r} (have: "
+                             f"{sorted(RUNTIMES)})")
+        kw = dict(backend_kw or {}, stream=RUNTIMES[runtime])
         if nts:
             kw["nts"] = nts
         n_shards = dep["shards"]

@@ -55,7 +55,8 @@ def run_cell(cell: dict, bench_spec: dict, seed: int, seconds: float,
     devices = list(devices if devices is not None else jax.devices())
     used = devices[:config["deployment"]["shards"]]
     bench = Bench(config, seed, devices=used, backend_kw=backend_kw,
-                  nts=nts, check_per_tenant=int(traffic["check_per_tenant"]))
+                  nts=nts, check_per_tenant=int(traffic["check_per_tenant"]),
+                  runtime=traffic.get("runtime", "batch"))
     loop = spec.load_loop(traffic["loop"], root).Loop(bench, traffic)
     t_deployed = time.perf_counter()
     loop.warm()

@@ -6,8 +6,9 @@ reads only spans that never nest; this module reads the host line (thread)
 that holds ``chipbench.window`` again, nested spans and all:
 
   phases   host seconds inside each ``repro.`` span within the window, and
-           the real and bucket rows of every ``repro.compute.launch`` that
-           starts in it (its ``rows`` and ``bucket`` arguments);
+           the real and bucket rows and the host bytes sent of every
+           ``repro.compute.launch`` that starts in it (its ``rows``,
+           ``bucket`` and ``h2d_bytes`` arguments);
   flatten  the line's spans as disjoint segments, each named after the
            innermost span that covers it;
   reduce   ``trace.reduce`` with every idle interval split over those
@@ -51,6 +52,9 @@ class Phases:
     #: real rows and bucket rows over the launches that start in the window
     rows: int
     bucket_rows: int
+    #: host bytes those launches sent to the device, or None where none of
+    #: them carries the ``h2d_bytes`` argument (a program without it)
+    h2d_bytes: int | None = None
 
     @property
     def window_s(self) -> float:
@@ -72,14 +76,19 @@ def host_lines(data) -> list[list[tuple[float, float, str, dict | None]]]:
 def phases(data) -> Phases | None:
     """The spans of the line that holds ``chipbench.window``, or None where
     no line does.  Spans on other lines are not read."""
-    for line in host_lines(data):
+    return of_lines(host_lines(data))
+
+
+def of_lines(lines) -> Phases | None:
+    """:func:`phases` of host lines as :func:`host_lines` gives them."""
+    for line in lines:
         win = [(s, e) for s, e, name, _ in line if name == trace.WINDOW_SPAN]
         if win:
             break
     else:
         return None
     lo, hi = win[0]
-    spans, span_s, rows, bucket_rows = [], {}, 0, 0
+    spans, span_s, rows, bucket_rows, h2d = [], {}, 0, 0, None
     for s, e, name, args in line:
         if name == trace.WINDOW_SPAN:
             continue
@@ -90,7 +99,9 @@ def phases(data) -> Phases | None:
         if args is not None and lo <= s < hi:
             rows += int(args.get("rows", 0))
             bucket_rows += int(args.get("bucket", 0))
-    return Phases((lo, hi), spans, span_s, rows, bucket_rows)
+            if "h2d_bytes" in args:
+                h2d = (h2d or 0) + int(args["h2d_bytes"])
+    return Phases((lo, hi), spans, span_s, rows, bucket_rows, h2d)
 
 
 def flatten(spans) -> list[tuple[float, float, str]]:

@@ -4,7 +4,8 @@
 each metric.  Every one of them is a file of its own, found by that name:
 
   configs/<config>.json    the deployment, its source and its cuts
-  traffic/<mix>.json       the traffic mix; its ``loop`` names the module
+  traffic/<mix>.json       the traffic mix; its ``loop`` names the module,
+                           its ``runtime`` the engine (``cell.RUNTIMES``)
   loops/<loop>.py          a loop kind: ``warm(bench, traffic)`` and
                            ``window(bench, traffic, seconds)``
   metrics/<metric>.py      a reader: ``read(record) -> float | None``

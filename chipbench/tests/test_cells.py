@@ -6,10 +6,13 @@ and every end-to-end metric reader.  Times from here are not
 speed."""
 from __future__ import annotations
 
+import json
+import shutil
+
 import numpy as np
 import pytest
 
-from chipbench import spec
+from chipbench import harness, spec
 from chipbench.cell import (STAMP_HEADER_WORD, STAMP_PAYLOAD_WORD, Bench,
                             make_packets, seed_rng, stamp)
 from chipbench.tests import tiny
@@ -37,6 +40,77 @@ def test_cell_runs_correct_with_its_end_to_end_metrics(cell, root,
     assert out["checks"]["checked_pkts"]["value"] > 0
     dev = out["device"]
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(dev)
+
+
+def test_a_configuration_added_as_files_alone_runs_whole(tmp_path):
+    """A checkout whose benchmark gains one configuration file, named in no
+    table here, and its ``configs`` and ``workloads`` entries: the test
+    root cuts it by its keys and the cell runs whole and correct."""
+    src = tmp_path / "checkout" / "chipbench"
+    for d in ("configs", "traffic", "loops", "metrics"):
+        shutil.copytree(spec.HERE / d, src / d)
+    shutil.copy(spec.HERE / "peaks.json", src / "peaks.json")
+    cfg = spec.load_config("vpc8-r1k")
+    cfg["deployment"].update(
+        tenants=5, rules_per_tenant=10000, weights={"pattern": [3, 1]},
+        chains={"firewall>>nat>>chacha20": 3, "firewall>>nat": 2})
+    (src / "configs" / "novel5.json").write_text(json.dumps(cfg))
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({"name": "novel5", "source": "a test",
+                             "file": "chipbench/configs/novel5.json",
+                             "reduced": [], "why": "a test"})
+    cell = {"name": "novel5.backlog", "config": "novel5",
+            "traffic": "backlog-16k", "chips": 1, "why": "a test"}
+    bench["workloads"].append(cell)
+    (src.parent / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    root = tiny.make_root(tmp_path / "tiny", src=src)
+    cut = spec.load_config("novel5", root)
+    dep = cut.pop("deployment")
+    assert cut == {k: v for k, v in cfg.items() if k != "deployment"}
+    assert set(dep) == set(cfg["deployment"])
+    assert {k for k in dep if dep[k] != cfg["deployment"][k]} == \
+        {"tenants", "rules_per_tenant", "chains"}
+    assert (dep["tenants"], dep["rules_per_tenant"], dep["shards"]) == \
+        (2, 16, 1)
+    assert dep["chains"] == {"firewall>>nat>>chacha20": 1,
+                             "firewall>>nat": 1}
+    out = tiny.run(cell, root, False, tmp_path / "trace",
+                   bench=spec.load_benchmark(src.parent))
+    assert out["correct"], out["checks"]
+    assert out["failed"] == 0 and out["attempted"] > 0
+    assert set(out["metrics"]) == {m["name"] for m in spec.metrics_for(
+        bench, cell["name"], "end_to_end")}
+
+
+@pytest.mark.parametrize("name", ["vpc8-r1k.backlog", "vpc8-r1k.stream"])
+def test_the_traffic_names_the_runtime(name, root, tmp_path, monkeypatch):
+    """A mix's ``runtime`` builds ``ComputeBackend(stream=...)``: the
+    streaming engine fills its dispatch ring, and a mix without the key
+    builds the batch engine, which never touches the ring."""
+    benches = []
+
+    class Recorded(harness.Bench):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            benches.append(self)
+    monkeypatch.setattr(harness, "Bench", Recorded)
+    cell = spec.find_cell(BENCH, name)
+    stream = spec.load_traffic(cell["traffic"]).get("runtime") == "stream"
+    out = tiny.run(cell, root, False, tmp_path / "trace")
+    assert out["correct"], out["checks"]
+    (compute,) = benches[0].computes
+    assert compute.stream is stream
+    assert (compute.stats["stream_batches"] > 0) is stream
+    assert (compute.ring.allocs > 0) is stream
+    if stream:
+        assert compute.ring.reuses > 0
+
+
+def test_an_unknown_runtime_is_an_error(root):
+    config = spec.load_config("vpc8-r1k", root)
+    with pytest.raises(ValueError, match="runtime"):
+        Bench(config, 1, runtime="pipelined")
 
 
 def test_fleet_cell_runs_correct_over_four_shards(root, tmp_path):

@@ -3,9 +3,10 @@
 Each run here is a whole cell at a small size on the CPU (``tiny.py``),
 the look for a chip skipped, with the timed path broken underneath:
 the control (the firewall's scan skipped), and each fault a packet path
-can have: a step that hands back its input unchanged, half of each batch
-left unprocessed, one shard of the fleet never run, one answer altered
-where it is produced.  A sound run of the same cell reads correct."""
+can have, in the batch and in the streaming engine: a step that hands back
+its input unchanged, half of each batch left unprocessed, one shard of the
+fleet never run, one answer altered where it is produced.  A sound run of
+the same cell reads correct."""
 from __future__ import annotations
 
 import jax
@@ -19,6 +20,7 @@ from chipbench.tests import tiny
 BENCH = spec.load_benchmark()
 VPC = spec.find_cell(BENCH, "vpc8-r1k.backlog")
 POISSON = spec.find_cell(BENCH, "vpc8-r1k.poisson80")
+STREAM = spec.find_cell(BENCH, "vpc8-r1k.stream")
 
 
 @pytest.fixture(scope="module")
@@ -65,27 +67,42 @@ def one_answer_altered(before, out):
     return {**out, "payload": out["payload"].at[0, 0].add(jnp.uint32(1))}
 
 
-@pytest.mark.parametrize("cell", [VPC, POISSON], ids=lambda c: c["name"])
+@pytest.mark.parametrize("cell", [VPC, POISSON, STREAM],
+                         ids=lambda c: c["name"])
 def test_sound_run_is_correct(cell, root):
     assert run(cell, root)["correct"]
 
 
-@pytest.mark.parametrize("cell", [VPC, POISSON], ids=lambda c: c["name"])
+@pytest.mark.parametrize("cell", [VPC, POISSON, STREAM],
+                         ids=lambda c: c["name"])
 def test_control_is_not_correct(cell, root):
     out = run(cell, root, nts=control_nts(), backend_kw=CONTROL_BACKEND)
     assert not out["correct"]
     assert out["checks"]["mismatched_words"]["value"] > 0
 
 
-@pytest.mark.parametrize("fault", [unchanged, half_left_out,
-                                   one_answer_altered],
-                         ids=lambda f: f.__name__)
-def test_fault_is_not_correct(fault, root, monkeypatch):
+FAULTS = [unchanged, half_left_out, one_answer_altered]
+
+
+def run_with_fault(cell, fault, root, monkeypatch):
     from repro.api.compute_backend import ComputeBackend
     monkeypatch.setattr(ComputeBackend, "_launch", _launch_with(fault))
-    out = run(VPC, root)
+    out = run(cell, root)
     assert not out["correct"]
     assert out["checks"]["mismatched_words"]["value"] > 0
+
+
+@pytest.mark.parametrize("fault", FAULTS, ids=lambda f: f.__name__)
+def test_fault_is_not_correct(fault, root, monkeypatch):
+    run_with_fault(VPC, fault, root, monkeypatch)
+
+
+@pytest.mark.parametrize("fault", FAULTS, ids=lambda f: f.__name__)
+def test_fault_in_the_streaming_engine_is_not_correct(fault, root,
+                                                      monkeypatch):
+    """The same faults where the streaming engine launches: it calls the
+    same ``_launch`` from its dispatch ring."""
+    run_with_fault(STREAM, fault, root, monkeypatch)
 
 
 def test_shard_never_run_is_not_correct(root, monkeypatch):

@@ -1,7 +1,8 @@
-"""The reader ``h2d_bytes_per_pkt``: on launch-span lists built here with
-known sums, on a trace whose launches carry no ``h2d_bytes`` (a program
-from before the argument), and in a traced run of every cell that lists it
-at the test size (``tiny.py``)."""
+"""The window's ``h2d_bytes`` that ``phases`` sums and the reader
+``h2d_bytes_per_pkt`` divides: on launch-span lists built here with known
+sums, on a trace whose launches carry no ``h2d_bytes`` (a program from
+before the argument), and in a traced run of every cell that lists the
+reader at the test size (``tiny.py``)."""
 from __future__ import annotations
 
 import pytest
@@ -18,7 +19,8 @@ ROW_BYTES = 5 * 4 + 16 * 4 + 4 + 1
 
 
 def window_bytes(lines):
-    return spec.load_metric(READER).window_bytes(lines)
+    ph = phases.of_lines(lines)
+    return None if ph is None else ph.h2d_bytes
 
 
 def test_sums_the_launches_that_start_in_the_window():
