@@ -229,7 +229,8 @@ BUILTIN_COMPUTE_NTS: dict[str, ComputeNT] = {
     "firewall": ComputeNT(
         "firewall", _fw_nt, writes=("allow",), reads=("headers",),
         schema=(("headers", (5,), "uint32"), ("allow", (), "bool")),
-        # fused-kernel share: header tile + rule rows + verdict tile
+        # fused-kernel share: packet tiles, the largest rule tile, the
+        # running best and the scan (any table streams in such tiles)
         tile_bytes=_vpc_tile() - _chacha_tile(block_n=256)),
     "nat": ComputeNT(
         "nat", _nat_nt, writes=("headers",), reads=("headers",),
@@ -314,6 +315,15 @@ class _Deployment:
     #: pinned device -> ``params`` with its arrays committed there, so a
     #: dispatch to a pinned device never copies rules and keys per launch
     device_params: dict = field(default_factory=dict)
+    #: rules each packet is matched against (0 with no firewall)
+    n_rules: int = 0
+
+
+def _n_rules(dag: NTDag, params: dict) -> int:
+    """Rules in the deployment's firewall table, 0 where it has none."""
+    if "firewall" not in dag.all_nts():
+        return 0
+    return int(np.shape(params["firewall"]["rules"][0])[0])
 
 
 def _commit(params: dict, dev) -> dict:
@@ -577,7 +587,9 @@ class ComputeBackend:
                       # bucket rows, and pad rows among them, over launches
                       "rows_launched": 0, "pad_rows": 0,
                       # host bytes staging sent to the device
-                      "h2d_bytes": 0}
+                      "h2d_bytes": 0,
+                      # real rows times the firewall's rules, over launches
+                      "rule_pairs": 0}
         #: host seconds inside each of the runtime's spans (``repro.obs``)
         self.span_s = dict.fromkeys(obs.PHASES, 0.0)
         #: batches fully dispatched + synced (I-BATCH conservation: this +
@@ -718,7 +730,8 @@ class ComputeBackend:
         self.deployments[dag.uid] = _Deployment(
             dag, params, fused, self._composed_program(dag),
             device_params={d: _commit(params, d)
-                           for d in self.devices or ()})
+                           for d in self.devices or ()},
+            n_rules=_n_rules(dag, params))
 
     def inject(self, tenant: str, dag_uid: int, state: dict | None = None,
                **fields) -> None:
@@ -874,13 +887,15 @@ class ComputeBackend:
                 and "allow" not in batches[0] else "composed")
         h2d = sum(v.nbytes for v in state.values()
                   if getattr(v, "ndim", 0) >= 1)   # the staged rows
+        pairs = n * dep.n_rules                    # the firewall's scan
         with obs.span(obs.LAUNCH, self.span_s, rows=n, bucket=bucket,
-                      h2d_bytes=h2d):
+                      h2d_bytes=h2d, rule_pairs=pairs):
             out = self._get_program(dep, bucket, path)(state, params)
         self.stats["dispatches"] += 1
         self.stats["rows_launched"] += bucket
         self.stats["pad_rows"] += bucket - n
         self.stats["h2d_bytes"] += h2d
+        self.stats["rule_pairs"] += pairs
         if path == "fused":
             self.stats["fused_dispatches"] += 1
         return out

@@ -120,6 +120,51 @@ class TestVerifierSignatures:
         dag = NTDag(1, "a", ((("firewall", "nat", "chacha20"),),))
         assert "V-BUDGET-VMEM" not in rules_of(verify(dag, backend=be))
 
+    def test_single_rule_block_of_20k_rules_over_budget(self):
+        """A megakernel that held a 20,000-rule table as one block, scanned
+        whole, would need ``(256, 20000)`` intermediates: over the budget,
+        as the chip's compiler found for such a kernel (out of VMEM from
+        17,500 rules at 256-packet tiles)."""
+        from repro.core.vmem import VMEM_BUDGET_BYTES
+        from repro.kernels.vpc_datapath.ops import LANES, vmem_tile_bytes
+        lanes = -(-20000 // LANES) * LANES
+        # one (256, R) int32 intermediate, beside the four (1, R) rule
+        # rows, each padded to 8 sublanes and double-buffered
+        whole = 4 * 256 * lanes + 2 * 4 * 4 * 8 * lanes
+        assert whole > VMEM_BUDGET_BYTES >= vmem_tile_bytes(256)
+        be = self._backend(
+            fw1=ComputeNT("fw1", lambda s, p: {}, writes=("allow",),
+                          tile_bytes=whole))
+        dag = NTDag(1, "a", ((("fw1",),),))
+        assert "V-BUDGET-VMEM" in rules_of(verify(dag, backend=be))
+
+    def test_rule_tiled_firewall_admits_100k_rules(self):
+        """The firewall's count is the kernel's at its largest rule tile,
+        which bounds its VMEM for a table of any size: a 100,000-rule
+        tenant is admitted in strict mode, and its own tile fits the
+        count."""
+        from repro.api.compute_backend import BUILTIN_COMPUTE_NTS as NTS
+        from repro.kernels.vpc_datapath.ops import (MAX_RULE_TILE,
+                                                    rule_tile_for,
+                                                    vmem_tile_bytes)
+        assert NTS["firewall"].tile_bytes + NTS["chacha20"].tile_bytes == \
+            vmem_tile_bytes(256, MAX_RULE_TILE)
+        assert rule_tile_for(100_000) <= MAX_RULE_TILE
+        assert vmem_tile_bytes(256, rule_tile_for(100_000)) <= \
+            vmem_tile_bytes(256, MAX_RULE_TILE)
+        rng = np.random.default_rng(0)
+        masks = np.full(100_000, 0xFFFF0000, np.uint32)
+        rules = (rng.integers(0, 2 ** 32, 100_000, dtype=np.uint32) & masks,
+                 masks, rng.random(100_000) < 0.5)
+        plat = Platform(ComputeBackend(use_fused=True), specs=VPC_SPECS,
+                        strict=True)
+        plat.tenant("t").deploy(
+            nt("firewall") >> nt("nat") >> nt("chacha20"),
+            params={"firewall": {"rules": rules}, "nat": {"nat_ip": 1},
+                    "chacha20": {"key": np.arange(8, dtype=np.uint32),
+                                 "nonce": np.arange(3, dtype=np.uint32)}})
+        assert "V-BUDGET-VMEM" not in rules_of(plat.admission_log)
+
 
 class TestVerifierResources:
     def test_capacity_warning_not_error(self):

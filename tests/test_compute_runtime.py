@@ -268,6 +268,32 @@ class TestComputeRuntime:
         assert stats["dispatches"] == 1
         assert (stats["rows_launched"], stats["pad_rows"]) == (bucket, pad)
 
+    @pytest.mark.parametrize("firewall", [True, False])
+    def test_launches_count_rule_pairs(self, firewall, tmp_path):
+        """``stats["rule_pairs"]`` and each launch span's ``rule_pairs``
+        argument are the launch's real rows times the deployment's rules,
+        0 for a chain with no firewall; pad rows count for nothing."""
+        import jax
+        from jax.profiler import ProfileData
+        plat = Platform(ComputeBackend(use_fused=False), specs=VPC_SPECS)
+        chain = VPC if firewall else nt("nat") >> nt("chacha20")
+        dep = plat.tenant("t").deploy(chain, params=PARAMS)
+        sizes = [7, 3, 8]                 # one bucket: one compile
+        jax.profiler.start_trace(str(tmp_path))
+        for i, n in enumerate(sizes):
+            h, p = make_packets(n, seed=90 + i)
+            dep.inject(headers=h, payload=p)
+            plat.run()                    # one launch per run
+        jax.profiler.stop_trace()
+        n_rules = RULES[0].shape[0] if firewall else 0
+        assert plat.backend.stats["rule_pairs"] == sum(sizes) * n_rules
+        [path] = tmp_path.glob("**/*.xplane.pb")
+        spans = [dict(e.stats)["rule_pairs"]
+                 for plane in ProfileData.from_file(str(path)).planes
+                 for line in plane.lines for e in line.events
+                 if e.name == obs.LAUNCH]
+        assert spans == [n * n_rules for n in sizes]
+
     @pytest.mark.parametrize("stream", [False, True])
     def test_run_fills_the_phase_totals(self, stream):
         """Both engines time the same five spans, and only those."""

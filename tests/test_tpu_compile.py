@@ -51,8 +51,11 @@ def _rules(sharding, n_rules):
 
 
 @pytest.mark.parametrize("n,n_rules", [(8, 32), (16384, 1024),
-                                       (65536, 1024)])
+                                       (65536, 1024), (16384, 100000),
+                                       (8192, 100000)])
 def test_vpc_datapath_compiles_for_v5e(one_chip, n, n_rules):
+    """At 100,000 rules the table streams through the kernel in rule
+    tiles; a single block of it would not fit VMEM."""
     def step(headers, payload, rules, key, nonce):
         return vpc_datapath(headers, payload, rules, key, nonce,
                             interpret=False)
@@ -70,8 +73,19 @@ def test_fused_runtime_program_compiles_for_v5e(one_chip, monkeypatch):
     chacha20`` at one pad bucket.  The kernel wrapper picks interpret mode
     from ``jax.default_backend()``, which is the CPU here, so the test
     reports the chip's backend to it."""
+    _compile_fused_program(one_chip, monkeypatch, n_rules=1024)
+
+
+def test_fused_runtime_program_compiles_for_v5e_at_100k_rules(one_chip,
+                                                               monkeypatch):
+    """The same program for a tenant of 100,000 rules, whose table the
+    kernel streams in rule tiles."""
+    _compile_fused_program(one_chip, monkeypatch, n_rules=100000)
+
+
+def _compile_fused_program(one_chip, monkeypatch, n_rules):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    bucket, n_rules = 16384, 1024
+    bucket = 16384
     params = {"firewall": {"rules": _rules(one_chip, n_rules)},
               "nat": {"nat_ip": _spec(one_chip, (), jnp.int32)},
               "chacha20": {"key": _spec(one_chip, (8,)),
